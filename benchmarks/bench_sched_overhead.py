@@ -422,17 +422,20 @@ def run_eps_sweep(
     return rows
 
 
-def accel_matrix():
+def accel_matrix(quick: bool = True):
     """The accel bench sweep: seeds x policies x error alphas on the
-    quick-scale paper FB workload — 4 x 4 x 4 = 64 cells, the matrix
-    size the whole-sweep-on-accelerator acceptance pins (every cell is
-    accel-eligible by construction)."""
+    paper FB workload — 4 x 4 x 4 = 64 cells, the matrix size the
+    whole-sweep-on-accelerator acceptance pins (every cell is
+    accel-eligible by construction).  ``quick`` is the quick bench's
+    30-job, 20-machine trace; ``quick=False`` is the paper's Sect. 4.1
+    cell (100 jobs on 100 machines), which chip_smoke.py runs."""
     from repro.scenarios.presets import paper_fb_base
     from repro.scenarios.spec import SweepSpec
 
+    base = paper_fb_base(seed=0)
     return SweepSpec(
         name="accel-bench",
-        base=paper_fb_base(seed=0).quick(),
+        base=base.quick() if quick else base,
         grids=(SweepSpec.grid(**{
             "workload.seed": (0, 1, 2, 3),
             "scheduler.policy": ("fifo", "hfsp", "srpt", "las"),
@@ -449,10 +452,14 @@ def run_accel_sweep(
     one jitted vmapped batch, the pool runs one spawned Python attempt
     per cell).
 
-    Returns None (and the quick bench records nothing) when jax is
-    unavailable.  The accel side reports both the cold wall (includes
-    jit compile) and the warm wall (compile cached — the steady-state
-    number, same compile-before-timing convention as waterfill_cell);
+    Returns None (and the quick bench records nothing) only when jax is
+    not importable; an error of the accel path itself propagates.  The
+    accel side reports both the cold wall (``accel_cold_s``: the first
+    call in the process, which compiles or, when the entry point's
+    persistent compile cache already holds the programs, loads them —
+    cold or cache hit) and the warm wall (compiled in-process — the
+    steady-state number, same compile-before-timing convention as
+    waterfill_cell);
     ``cells_per_s`` and the headline ``speedup_vs_pool`` use the warm
     wall.  The pool side measures ``n_pool_cells`` cells of the same
     matrix (per-cell cost is flat across the grid — no compile to
@@ -460,11 +467,9 @@ def run_accel_sweep(
     pool throughput without burning a minute of quick-bench budget) and
     the record carries ``pool_cells_measured`` so the extrapolation is
     explicit."""
-    try:
-        from repro.accel import have_jax
-        from repro.accel.sweep_exec import run_cells_accel
-    except Exception:
-        return None
+    from repro.accel import have_jax
+    from repro.accel.sweep_exec import run_cells_accel
+
     if not have_jax():
         return None
     from repro.scenarios.sweep import run_sweep

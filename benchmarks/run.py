@@ -150,7 +150,8 @@ def quick_sched_wall(json_path: str | None = None, seed: int = 0) -> dict:
         )
     # Whole-sweep-on-accelerator throughput at the 64-cell matrix
     # (vmapped device program vs process-pool runner, cells/sec) —
-    # bench_gate.py enforces a same-record speedup floor on it.
+    # bench_gate.py enforces a same-record speedup floor on it.  Only a
+    # missing jax skips it; an accel error fails the bench.
     accel = bench_sched_overhead.run_accel_sweep()
     if accel is not None:
         record["accel_sweep"] = {
@@ -158,7 +159,7 @@ def quick_sched_wall(json_path: str | None = None, seed: int = 0) -> dict:
             for k, v in accel.items()
         }
     else:
-        print("# accel sweep: skipped (jax unavailable)", flush=True)
+        print("# accel sweep: skipped (jax not importable)", flush=True)
     record["scenarios"] = scenario_smoke()
     if json_path:
         with open(json_path, "w") as f:
@@ -210,6 +211,9 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="with --quick: dump per-scheduler wall-clock JSON")
     args = ap.parse_args()
+    from repro.utils.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.quick:
         quick_sched_wall(json_path=args.json)

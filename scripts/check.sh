@@ -76,8 +76,8 @@ PY
   echo
   echo "== accel sweep smoke (vmapped executor, Pallas water-fill) =="
   # A small accel batch end-to-end: eligible cells run as one vmapped
-  # device program, the Pallas interpret-mode water-fill must be
-  # bit-identical to the lax reference on a random row, and an
+  # device program, the Pallas water-fill in interpret mode must be
+  # bit-identical to the f32 lax reference on a random row, and an
   # ineligible spec must report a fallback reason.  Skips cleanly when
   # jax is absent (the conformance pins live in tests/test_accel.py).
   python - <<'PY'
@@ -105,10 +105,11 @@ rng = np.random.default_rng(0)
 caps = rng.integers(1, 30, 32).astype(float)
 ws = np.ones(32)
 live = rng.random(32) < 0.8
-pallas = water_fill_host(caps, ws, 40.0, live, impl="pallas")
+pallas = water_fill_host(caps, ws, 40.0, live, impl="pallas_interpret")
 lax = water_fill_host(caps, ws, 40.0, live, impl="lax")
-assert pallas.tobytes() == lax.tobytes(), "pallas != lax bitwise"
-print("accel smoke: pallas water-fill bit-identical to lax reference")
+assert pallas.tobytes() == lax.tobytes(), "pallas != f32 lax bitwise"
+print("accel smoke: pallas water-fill (interpret) bit-identical to the "
+      "f32 lax reference")
 ok, reason = accel_eligible(
     paper_fb_base(seed=0).quick().override(**{"scheduler.policy": "fair"})
 )

@@ -70,6 +70,12 @@ _PARAM_KEYS = (
 )
 
 
+class CellBatchNotConverged(RuntimeError):
+    """A packed batch did not drain within its chunk budget — the one
+    accel failure the distributed worker demotes to the Python path
+    (every other error, a device or compile fault included, fails it)."""
+
+
 @functools.lru_cache(maxsize=1)
 def _modules():
     try:
@@ -306,7 +312,7 @@ def _make_water_fill(impl: str):
     if impl == "lax":
         return wf.water_fill_lax
     if impl == "pallas":
-        return functools.partial(wf.water_fill_pallas, interpret=None)
+        return wf.water_fill_pallas
     if impl == "pallas_interpret":
         return functools.partial(wf.water_fill_pallas, interpret=True)
     if impl == "none":
@@ -351,13 +357,14 @@ def run_packed(
     later chunks.  ``on_chunk(chunks_done, cells_done, cells_total)``
     runs between chunks — the distributed-sweep worker renews its
     leases there.  Raises if ``max_chunks`` scans don't drain the batch
-    (a liveness bug, not a tuning knob — every real cell terminates)."""
+    (a liveness bug, not a tuning knob — every real cell terminates):
+    :class:`CellBatchNotConverged`."""
     jax, jnp, _ = _require()
     width = int(np.asarray(params["arrival"]).shape[-1])
     runner = _chunk_runner(width, int(t_chunk), impl)
     state = init_state(params)
     b = state["t"].shape[0]
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         params_j = {k: jnp.asarray(v) for k, v in params.items()}
         state = {k: jnp.asarray(v) for k, v in state.items()}
         for chunk in range(max_chunks):
@@ -370,7 +377,7 @@ def run_packed(
             if cell_done.all():
                 break
         else:
-            raise RuntimeError(
+            raise CellBatchNotConverged(
                 f"accel cell batch did not converge in {max_chunks} "
                 f"chunks of {t_chunk} steps"
             )

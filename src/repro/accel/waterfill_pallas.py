@@ -1,36 +1,37 @@
-"""The water-fill fixpoint as a real Pallas kernel.
+"""The water-fill fixpoint as a Pallas TPU kernel.
 
 ``repro.core.vcluster_jax._water_fill_masked`` expresses the weighted
 max-min fixpoint as a ``lax.while_loop``: fill proportionally to weight,
 clamp at cap, redistribute the overflow.  That is the one genuinely
 iterative kernel left in the scheduler inner loop, and the accel cell
 step (:mod:`repro.accel.cell_step`) evaluates it every step for every
-cell in the vmapped batch — exactly the shape a fused on-chip kernel
-wants.
+cell in the vmapped batch.
 
-This module re-expresses the *same* fixpoint as a Pallas kernel.  Two
-structural changes, zero numeric ones:
+This module re-expresses the same fixpoint as a Pallas kernel shaped for
+the TPU's compiler (Mosaic):
 
-* **Bounded rounds instead of a dynamic ``while_loop``.**  Every
-  continuing round caps at least one active entry, so the fixpoint
-  converges in at most ``width`` rounds; the kernel runs a
-  ``fori_loop`` of ``width + 1`` rounds (the ``+1`` absorbs the
-  terminal everyone-keeps-their-share round).
-* **A guarded body.**  Each round carries the reference loop's *cond*
-  (``free > eps and any(active)``) as a ``go`` flag and selects the old
-  state when it is False.  Running the raw body past convergence would
-  re-distribute residual free slots and break equality with the
-  reference — the guard makes every post-convergence round an exact
-  no-op, so the floating-point trajectory is operation-for-operation
-  the reference's and the outputs are *bit-identical* (pinned by
-  tests/test_accel.py).
+* **Rows in (8, W) blocks.**  The kernel takes the batch explicitly as a
+  2-D ``(B, W)`` operand, ``B`` padded to a multiple of 8, one grid
+  program per 8 rows; slots arrive as an ``(8, 1)`` column.  The cell
+  step calls the fill once per cell under ``vmap``, so the public entry
+  carries a ``custom_vmap`` rule that hands the whole batch to the 2-D
+  kernel (the default Pallas batching rule would give each program a
+  ``(Squeezed, W)`` block, which Mosaic refuses).
+* **float32.**  Mosaic lowers no 64-bit arithmetic.  The kernel computes
+  in f32, with the active set carried as an f32 mask (a bool loop carry
+  does not lower either); :func:`water_fill_pallas` casts the f64 cell
+  state to f32 at the call and back at the return.
+* **A guarded loop.**  Each row carries the reference loop's cond
+  (``free > eps and any(active)``) as a per-row flag and keeps its old
+  state once that goes False, so rows that converge early are exact
+  no-ops while the other rows of their block finish; the loop exits when
+  no row of the block is still going (at most ``W + 1`` rounds: every
+  continuing round caps at least one entry).
 
-On CPU the kernel runs in Pallas interpret mode (the documented CI
-fallback); on a real TPU backend ``interpret=None`` resolves to a
-compiled kernel.  The cell step defaults to the ``lax`` reference on
-CPU — interpret mode trades speed for fidelity to the Pallas
-programming model — and the conformance tests pin the two paths
-bit-equal so either can serve the batched executor.
+The contract is bit-equality with :func:`water_fill_lax` evaluated in
+f32 on the same rows (pinned in interpret mode by tests/test_accel.py).
+``impl="pallas"`` always compiles the kernel; interpret mode is only the
+explicit ``impl="pallas_interpret"`` (CPU tests).
 """
 
 from __future__ import annotations
@@ -38,6 +39,10 @@ from __future__ import annotations
 import functools
 
 import numpy as np
+
+#: Rows per grid program: the TPU's sublane count, the second-to-last
+#: block dimension Mosaic requires to be a multiple of 8.
+ROWS = 8
 
 
 @functools.lru_cache(maxsize=1)
@@ -49,10 +54,6 @@ def _modules():
     except Exception:  # pragma: no cover - environment without jax/pallas
         return None
     return jax, jnp, pl
-
-
-def have_pallas() -> bool:
-    return _modules() is not None
 
 
 def _require():
@@ -67,119 +68,181 @@ def _require():
 
 def water_fill_lax(caps, ws, slots, live):
     """The ``lax.while_loop`` reference (re-exported for the dispatch
-    seam and the bit-equivalence tests)."""
+    seam and the equivalence tests)."""
     from repro.core.vcluster_jax import _water_fill_masked
 
     return _water_fill_masked(caps, ws, slots, live)
 
 
-@functools.lru_cache(maxsize=None)
-def _pallas_fill(width: int, interpret: bool):
-    """Build the Pallas water-fill for one padded ``width``.
+def _kernel(caps_ref, ws_ref, live_ref, slots_ref, out_ref):
+    """One (8, W) block of rows; every per-row scalar is an (8, 1)
+    column.  Operation for operation the reference loop's body."""
+    jax, jnp, _ = _modules()
+    width = caps_ref.shape[-1]
+    live = live_ref[...] > 0.5
+    w = jnp.where(live, ws_ref[...], 0.0)
+    c = jnp.where(live, caps_ref[...], 0.0)
 
-    A new width (or interpret flag) is a new kernel — widths come from
-    the same ``_bucket`` quantization the vcluster backend uses, so the
-    cache stays tiny.  Inputs are flat ``(width,)`` f64 rows plus a
-    ``(1,)`` slots scalar; ``live`` travels as an f64 mask so every
-    operand shares one dtype.
-    """
+    def row_sum(x):
+        return jnp.sum(x, axis=-1, keepdims=True)
+
+    def row_any(mask):
+        return jnp.max(mask, axis=-1, keepdims=True) > 0.5
+
+    def as_mask(b):  # explicit f32: under x64 bare 1.0/0.0 would be f64
+        return jnp.where(b, jnp.float32(1.0), jnp.float32(0.0))
+
+    def going(active, free):
+        return (free > 1e-12) & row_any(active)
+
+    def cond(state):
+        it, _, active, free = state
+        return (it <= width) & (jnp.max(as_mask(going(active, free))) > 0.5)
+
+    def body(state):
+        it, alloc, active_f, free = state
+        go = going(active_f, free)
+        active = active_f > 0.5
+        w_act = jnp.where(active, w, 0.0)
+        total_w = row_sum(w_act)
+        w_ok = total_w > 0.0
+        share = jnp.where(
+            active, free * w_act / jnp.where(w_ok, total_w, 1.0), 0.0
+        )
+        headroom = c - alloc
+        capped = active & (share >= headroom - 1e-12)
+        cont = w_ok & row_any(as_mask(capped))
+        grant_capped = jnp.where(capped, headroom, 0.0)
+        grant = jnp.where(cont, grant_capped, jnp.where(w_ok, share, 0.0))
+        alloc2 = alloc + grant
+        free2 = jnp.where(cont, free - row_sum(grant_capped), free)
+        active2 = as_mask(cont & active & ~capped)
+        return (
+            it + 1,
+            jnp.where(go, alloc2, alloc),
+            jnp.where(go, active2, active_f),
+            jnp.where(go, free2, free),
+        )
+
+    alloc0 = jnp.zeros_like(c)
+    active0 = as_mask(live & (c > 0.0))
+    state = (jnp.int32(0), alloc0, active0, slots_ref[...])
+    out_ref[...] = jax.lax.while_loop(cond, body, state)[1]
+
+
+@functools.lru_cache(maxsize=None)
+def _pallas_fill(rows: int, width: int, interpret: bool):
+    """The 2-D kernel for one padded ``(rows, width)`` batch: f32
+    ``caps``/``ws``/``live`` (mask) rows plus an ``(rows, 1)`` slots
+    column; ``rows`` is a multiple of :data:`ROWS`."""
     jax, jnp, pl = _require()
 
-    def kernel(caps_ref, ws_ref, live_ref, slots_ref, out_ref):
-        caps = caps_ref[...]
-        live = live_ref[...] > 0.5
-        w = jnp.where(live, ws_ref[...], 0.0)
-        c = jnp.where(live, caps, 0.0)
+    def rows_at(i):  # explicit i32: the f64 cell traces this under x64
+        return i, jnp.int32(0)
 
-        def body(_, state):
-            alloc, active, free = state
-            # The reference loop's cond, carried as a guard: once it
-            # goes False every later round selects the old state.
-            go = (free > 1e-12) & jnp.any(active)
-            w_act = jnp.where(active, w, 0.0)
-            total_w = jnp.sum(w_act)
-            w_ok = total_w > 0.0
-            share = jnp.where(
-                active, free * w_act / jnp.where(w_ok, total_w, 1.0), 0.0
-            )
-            headroom = c - alloc
-            capped = active & (share >= headroom - 1e-12)
-            cont = w_ok & jnp.any(capped)
-            grant_capped = jnp.where(capped, headroom, 0.0)
-            grant = jnp.where(
-                cont, grant_capped, jnp.where(w_ok, share, 0.0)
-            )
-            alloc2 = alloc + grant
-            free2 = jnp.where(cont, free - jnp.sum(grant_capped), free)
-            active2 = jnp.where(
-                cont, active & ~capped, jnp.zeros_like(active)
-            )
-            return (
-                jnp.where(go, alloc2, alloc),
-                jnp.where(go, active2, active),
-                jnp.where(go, free2, free),
-            )
-
-        alloc0 = jnp.zeros_like(caps)
-        active0 = live & (c > 0.0)
-        state = (alloc0, active0, slots_ref[0])
-        alloc, _, _ = jax.lax.fori_loop(0, width + 1, body, state)
-        out_ref[...] = alloc
-
+    row_block = pl.BlockSpec((ROWS, width), rows_at)
     return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((width,), jnp.float64),
+        _kernel,
+        grid=(rows // ROWS,),
+        in_specs=[
+            row_block,
+            row_block,
+            row_block,
+            pl.BlockSpec((ROWS, 1), rows_at),
+        ],
+        out_specs=row_block,
+        out_shape=jax.ShapeDtypeStruct((rows, width), jnp.float32),
         interpret=interpret,
+        name="water_fill",
     )
 
 
-def _resolve_interpret(interpret: bool | None) -> bool:
-    if interpret is not None:
-        return interpret
-    jax, _, _ = _require()
-    # Compiled Pallas needs a real accelerator backend; everywhere else
-    # (CPU CI included) the interpreter is the documented fallback.
-    return jax.default_backend() not in ("tpu",)
+def water_fill_rows(caps, ws, slots, live, *, interpret: bool = False):
+    """The kernel over a ``(B, W)`` batch of f32 rows (``live`` an f32
+    mask, ``slots`` a ``(B,)`` vector): pads ``B`` to a multiple of 8
+    with dead rows, which allocate nothing, and strips them again."""
+    _, jnp, _ = _require()
+    b, width = caps.shape
+    pad = -b % ROWS
+    rows = ((0, pad), (0, 0))
+    out = _pallas_fill(b + pad, width, interpret)(
+        jnp.pad(caps, rows),
+        jnp.pad(ws, rows),
+        jnp.pad(live, rows),
+        jnp.pad(slots.reshape(b, 1), rows),
+    )
+    return out[:b]
 
 
-def water_fill_pallas(caps, ws, slots, live, *, interpret: bool | None = None):
-    """Pallas water-fill over one padded row (traced; f64 in/out).
+@functools.lru_cache(maxsize=2)
+def _row_fill(interpret: bool):
+    """One f32 row in, one row out; under ``vmap`` the whole batch goes
+    to :func:`water_fill_rows` in one kernel call."""
+    jax, jnp, _ = _require()
 
-    Same signature and semantics as :func:`water_fill_lax`; safe under
-    ``vmap`` (the Pallas batching rule lifts the batch into the grid),
-    which is how the vmapped cell step consumes it.
+    @jax.custom_batching.custom_vmap
+    def fill(caps, ws, slots, live):
+        return water_fill_rows(
+            caps[None], ws[None], slots.reshape(1), live[None],
+            interpret=interpret,
+        )[0]
+
+    @fill.def_vmap
+    def _fill_batched(axis_size, in_batched, caps, ws, slots, live):
+        def rows(x, batched, shape):
+            return x if batched else jnp.broadcast_to(x, shape)
+
+        shape = (axis_size, caps.shape[-1])
+        out = water_fill_rows(
+            rows(caps, in_batched[0], shape),
+            rows(ws, in_batched[1], shape),
+            rows(slots, in_batched[2], (axis_size,)),
+            rows(live, in_batched[3], shape),
+            interpret=interpret,
+        )
+        return out, True
+
+    return fill
+
+
+def water_fill_pallas(caps, ws, slots, live, *, interpret: bool = False):
+    """Pallas water-fill over one padded row (traced).
+
+    Same signature and semantics as :func:`water_fill_lax`: ``live`` is a
+    bool mask, the result has ``caps``' dtype.  The kernel itself runs in
+    f32, so an f64 row is rounded at the call and widened at the return.
     """
     _, jnp, _ = _require()
-    fill = _pallas_fill(int(caps.shape[-1]), _resolve_interpret(interpret))
-    return fill(
-        caps,
-        jnp.asarray(ws, caps.dtype),
-        jnp.asarray(live, caps.dtype),
-        jnp.asarray(slots, caps.dtype).reshape(1),
+    f32 = jnp.float32
+    out = _row_fill(interpret)(
+        jnp.asarray(caps, f32),
+        jnp.asarray(ws, f32),
+        jnp.asarray(slots, f32),
+        jnp.where(live, 1.0, 0.0).astype(f32),
     )
+    return out.astype(caps.dtype)
 
 
-def water_fill_host(caps, ws, slots, live, *, impl: str = "pallas",
-                    interpret: bool | None = None) -> np.ndarray:
-    """numpy-in/numpy-out convenience wrapper (tests, notebooks)."""
+def water_fill_host(caps, ws, slots, live, *, impl: str = "pallas") -> np.ndarray:
+    """numpy-in/numpy-out convenience wrapper (tests, notebooks), in f32.
+
+    ``impl`` is ``"pallas"`` (compiled kernel), ``"pallas_interpret"``
+    (the kernel in Pallas interpret mode) or ``"lax"`` (the reference).
+    Every impl is evaluated in float32, the kernel's own precision, so
+    the three are compared at one precision."""
     jax, jnp, _ = _require()
-    caps = np.asarray(caps, np.float64)
-    with jax.experimental.enable_x64():
-        if impl == "pallas":
-            out = water_fill_pallas(
-                jnp.asarray(caps),
-                jnp.asarray(np.asarray(ws, np.float64)),
-                float(slots),
-                jnp.asarray(np.asarray(live, bool)),
-                interpret=interpret,
-            )
+    caps = np.asarray(caps, np.float32)
+    ws = np.asarray(ws, np.float32)
+    live = np.asarray(live, bool)
+    # Traced under x64, as the f64 cell traces the kernel.
+    with jax.enable_x64(True):
+        caps, ws, live = map(jnp.asarray, (caps, ws, live))
+        slots = jnp.asarray(slots, jnp.float32)
+        if impl in ("pallas", "pallas_interpret"):
+            out = water_fill_pallas(caps, ws, slots, live,
+                                    interpret=impl == "pallas_interpret")
         elif impl == "lax":
-            out = water_fill_lax(
-                jnp.asarray(caps),
-                jnp.asarray(np.asarray(ws, np.float64)),
-                jnp.asarray(float(slots), jnp.float64),
-                jnp.asarray(np.asarray(live, bool)),
-            )
+            out = water_fill_lax(caps, ws, slots, live)
         else:
             raise ValueError(f"unknown water-fill impl {impl!r}")
     return np.asarray(out)

@@ -30,7 +30,7 @@ padding is exact — padded entries contribute ``0.0`` terms to every sum
 and sort behind an ``inf`` rank, so the result on real entries is
 bit-identical across bucket sizes (adding a float zero is exact).
 
-Everything runs in float64 (via the scoped ``jax.experimental.enable_x64``
+Everything runs in float64 (via the scoped ``jax.enable_x64(True)``
 context, so the global x64 flag — and with it the rest of the process —
 is untouched) to stay within 1e-9 of the numpy reference.
 
@@ -299,7 +299,7 @@ def water_fill(caps: np.ndarray, ws: np.ndarray, slots: float) -> np.ndarray:
     width = _bucket(n)
     live = np.zeros(width, dtype=bool)
     live[:n] = True
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         out = _jitted()["fill"](
             _pad1(np.asarray(caps, np.float64), width),
             _pad1(np.asarray(ws, np.float64), width),
@@ -332,7 +332,7 @@ def water_fill_batch(
     live = np.zeros((b, width), dtype=bool)
     live[:, :n] = True
     slots_b = np.broadcast_to(np.asarray(slots, np.float64), (b,)).copy()
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         out = _jitted()["fill_batch"](
             np.pad(caps_b, pad),
             np.pad(np.asarray(ws_b, np.float64), pad),
@@ -373,7 +373,7 @@ def project_finish_times(
         present = np.zeros(width, dtype=bool)
         present[:m] = True
         floor = width // 2 if width >= 1024 else 0
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             state = _jitted()["project"](
                 _pad1(rem, width),
                 _pad1(caps, width),
@@ -436,7 +436,7 @@ def project_finish_times_batch(
             present[i, : int(nv)] = True
     slots_b = np.broadcast_to(np.asarray(slots, np.float64), (b,)).copy()
     now_b = np.broadcast_to(np.asarray(now, np.float64), (b,)).copy()
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         out = _jitted()["project_batch"](
             rem_p, caps_p, ws_p, present, slots_b, now_b
         )

@@ -220,6 +220,9 @@ def _cmd_replay(args) -> int:
 
 
 def main(argv: list[str] | None = None) -> int:
+    from repro.utils.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(prog="repro.scenarios", description=__doc__)
     sub = ap.add_subparsers(dest="cmd", required=True)
 

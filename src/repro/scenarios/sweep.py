@@ -43,7 +43,9 @@ backends), :mod:`repro.scenarios.lease` (claim/renew/release protocol)
 and :mod:`repro.scenarios.coordinator` (``sweep-status`` view).
 
 Workers use the ``spawn`` start method: the parent may hold jax state
-(the vcluster jax backend), which does not survive ``fork``.
+(the vcluster jax backend), which does not survive ``fork``.  Each
+attempt pins itself to the CPU backend, so a parent that owns the
+accelerator keeps it (one process per chip).
 """
 
 from __future__ import annotations

@@ -30,8 +30,9 @@ worker loops, and an orphaned child dies on its broken result pipe.
 This module also owns the per-attempt primitives (spawned process entry
 point, test-fault hooks, quarantine record) shared with the local
 supervisor — workers use the ``spawn`` start method because the parent
-may hold jax state (the vcluster jax backend), which does not survive
-``fork``.
+may hold jax state (the vcluster jax backend, an accel batch), which
+does not survive ``fork``, and each attempt runs on the CPU backend so
+the parent alone owns the accelerator.
 """
 
 from __future__ import annotations
@@ -39,6 +40,7 @@ from __future__ import annotations
 import json
 import os
 import socket
+import sys
 import time
 from pathlib import Path
 
@@ -98,7 +100,15 @@ def _apply_test_hook(cid: str) -> None:
 
 def _cell_worker(conn, cid: str, spec_dict: dict) -> None:
     """Spawned per-attempt process entry point: compute the cell, send
-    ("ok", report) or ("err", repr) back over the pipe."""
+    ("ok", report) or ("err", repr) back over the pipe.
+
+    The attempt is pinned to the CPU backend before anything can
+    initialise one: the parent may own the accelerator (an accel batch,
+    the jax virtual cluster), a chip belongs to one process at a time,
+    and a child that reached for it would fail or hang on its lock."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
     try:
         _apply_test_hook(cid)
         _, result = _run_cell((cid, spec_dict))
@@ -241,9 +251,11 @@ def run_worker(
     represent — fault injection, trace workloads, non-fluid generators —
     fall back to the unchanged per-cell spawned Python path, so a mixed
     sweep still converges; the fallback reasons are reported in the
-    summary's ``"accel_fallbacks"``.  A batch that dies on the device
-    (e.g. the chunk budget) demotes its cells to the Python path rather
-    than quarantining them.
+    summary's ``"accel_fallbacks"``.  A batch that exhausts its chunk
+    budget (:class:`~repro.accel.cell_step.CellBatchNotConverged`)
+    demotes its cells to the Python path rather than quarantining them;
+    any other accel error — a device or compile fault — fails the
+    worker, so a broken device path cannot pass as a Python sweep.
     """
     if compute not in ("python", "accel"):
         raise ValueError(f"unknown compute {compute!r}")
@@ -279,6 +291,7 @@ def run_worker(
 
     def _try_accel_batch(todo: list[str], held, now: float) -> bool:
         """Claim + run one vmapped batch; True if any cell was computed."""
+        from repro.accel.cell_step import CellBatchNotConverged
         from repro.accel.sweep_exec import run_cells_accel
 
         budget = accel_batch
@@ -317,7 +330,7 @@ def run_worker(
             batch = run_cells_accel(
                 [spec_of[cid] for cid in got], on_chunk=tick_all
             )
-        except Exception as e:  # noqa: BLE001 - demote batch to Python path
+        except CellBatchNotConverged as e:
             for cid in got:
                 accel_unfit[cid] = f"accel batch failed: {e!r}"
                 store.release(cid, hashes[cid], wid)

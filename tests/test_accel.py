@@ -13,9 +13,11 @@ the Python simulator on the golden traces:
   The bands were set from measured deltas with ~1.5-2x headroom — tight
   enough that a scheduling-logic bug (a wrong key, a starved phase)
   blows through them (docs/accel.md#fidelity);
-* **Pallas vs lax bit-equality** — the Pallas water-fill kernel
+* **Pallas vs lax bit-equality** — the f32 Pallas water-fill kernel
   (interpret mode on CPU) must match the ``lax.while_loop`` reference
-  bit for bit, standalone and through a whole cell run;
+  evaluated in f32 bit for bit, standalone and through a whole cell
+  run; the f32-kernel cell stays inside the hfsp fidelity band of the
+  f64 lax cell;
 * **padding neutrality** — widening the fixed-shape window must not
   change any output bit (padding rows are inert by construction);
 * **determinism** — repeated batched runs are bitwise identical;
@@ -23,6 +25,8 @@ the Python simulator on the golden traces:
   reason and routed through the Python runner by the sweep/worker
   entry points.
 """
+
+import os
 
 import numpy as np
 import pytest
@@ -104,9 +108,11 @@ def test_accel_preserves_policy_ordering(golden_runs):
 
 
 # ---------------------------------------------------------------------------
-# Pallas water-fill: bit-equality with the lax.while_loop reference
+# Pallas water-fill: bit-equality with the lax.while_loop reference at f32
 # ---------------------------------------------------------------------------
 def test_pallas_waterfill_bit_identical_random_rows():
+    """The kernel computes in f32 (Mosaic lowers no f64), so its
+    reference is the lax loop evaluated in f32 on the same rows."""
     rng = np.random.default_rng(7)
     for trial in range(25):
         width = int(rng.integers(1, 65))
@@ -115,9 +121,9 @@ def test_pallas_waterfill_bit_identical_random_rows():
         ws = rng.uniform(0.5, 2.0, width)
         live = rng.random(width) < 0.7
         slots = float(rng.uniform(0.0, caps.sum() * 1.2 + 1.0))
-        a = water_fill_host(caps, ws, slots, live, impl="pallas",
-                            interpret=True)
+        a = water_fill_host(caps, ws, slots, live, impl="pallas_interpret")
         b = water_fill_host(caps, ws, slots, live, impl="lax")
+        assert a.dtype == b.dtype == np.float32
         assert a.tobytes() == b.tobytes(), f"trial {trial}"
 
 
@@ -127,23 +133,51 @@ def test_pallas_waterfill_degenerate_rows():
     for slots, live in ((10.0, np.zeros(8, bool)),
                         (0.0, np.ones(8, bool)),
                         (1e6, np.ones(8, bool))):
-        a = water_fill_host(np.arange(8.0), ws, slots, live, impl="pallas",
-                            interpret=True)
+        a = water_fill_host(np.arange(8.0), ws, slots, live,
+                            impl="pallas_interpret")
         b = water_fill_host(np.arange(8.0), ws, slots, live, impl="lax")
         assert a.tobytes() == b.tobytes()
 
 
-def test_cell_run_pallas_interpret_matches_lax_bitwise():
+def test_cell_run_pallas_interpret_matches_lax_bitwise(monkeypatch):
     """A whole hfsp cell driven by the Pallas kernel (interpret mode)
-    must reproduce the lax-driven run bit for bit — the two impls are
-    interchangeable behind the dispatch seam."""
+    reproduces, bit for bit, the cell driven by the lax reference at
+    the kernel's f32 precision (rounded and widened at the same seam);
+    against the f64 lax cell it stays inside the hfsp fidelity band."""
+    import jax.numpy as jnp
+
+    from repro.accel import cell_step
+    from repro.accel.waterfill_pallas import water_fill_lax
+
+    def lax_f32(caps, ws, slots, live):
+        f32 = jnp.float32
+        return water_fill_lax(
+            caps.astype(f32), ws.astype(f32), jnp.asarray(slots, f32), live
+        ).astype(caps.dtype)
+
+    make = cell_step._make_water_fill
+    monkeypatch.setattr(
+        cell_step, "_make_water_fill",
+        lambda impl: lax_f32 if impl == "lax_f32" else make(impl),
+    )
     spec = paper_fb_base(seed=0).quick().override(
         **{"scheduler.policy": "hfsp"}
     )
     packed = pack_cells([synthesize_cell(spec)])
-    a = summarize(packed, run_packed(packed, impl="lax"))[0]
-    b = summarize(packed, run_packed(packed, impl="pallas_interpret"))[0]
-    assert a == b
+    try:
+        ref32 = summarize(packed, run_packed(packed, impl="lax_f32"))[0]
+    finally:
+        cell_step._chunk_runner.cache_clear()
+    ref64 = summarize(packed, run_packed(packed, impl="lax"))[0]
+    got = summarize(packed, run_packed(packed, impl="pallas_interpret"))[0]
+    assert got == ref32
+    assert got["jobs_completed"] == ref64["jobs_completed"]
+    assert got["mean_sojourn_s"] == pytest.approx(
+        ref64["mean_sojourn_s"], rel=SOJOURN_TOL["hfsp"]
+    )
+    assert got["makespan_s"] == pytest.approx(
+        ref64["makespan_s"], rel=MAKESPAN_TOL
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -266,3 +300,106 @@ def test_worker_compute_accel_converges_mixed_sweep(tmp_path):
     assert summary["accel_cells"] == 2
     assert summary["accel_batches"] == 1
     assert list(summary["accel_fallbacks"]) == ["scheduler.policy=fair"]
+
+
+def _fifo_sweep() -> SweepSpec:
+    return SweepSpec(
+        name="one",
+        base=paper_fb_base(seed=0).quick(),
+        grids=(SweepSpec.grid(**{"scheduler.policy": ("fifo",)}),),
+    )
+
+
+def test_worker_accel_device_error_fails_worker(tmp_path, monkeypatch):
+    """Only a batch that runs out of chunks is demoted to the Python
+    path; any other accel error (a device or compile fault) fails the
+    worker instead of finishing the sweep on the host."""
+    from repro.accel import sweep_exec
+    from repro.scenarios.worker import run_worker
+
+    def device_fault(*_a, **_k):
+        raise RuntimeError("device fault")
+
+    monkeypatch.setattr(sweep_exec, "run_cells_accel", device_fault)
+    with pytest.raises(RuntimeError, match="device fault"):
+        run_worker(_fifo_sweep(), tmp_path / "s.jsonl", compute="accel",
+                   deadline=60.0)
+
+
+def test_worker_accel_nonconvergence_demotes_to_python(tmp_path, monkeypatch):
+    from repro.accel import sweep_exec
+    from repro.accel.cell_step import CellBatchNotConverged
+    from repro.scenarios.worker import run_worker
+
+    def stuck(*_a, **_k):
+        raise CellBatchNotConverged("did not converge")
+
+    monkeypatch.setattr(sweep_exec, "run_cells_accel", stuck)
+    summary = run_worker(_fifo_sweep(), tmp_path / "s.jsonl",
+                         compute="accel", deadline=120.0)
+    assert summary["computed"] == ["scheduler.policy=fifo"]
+    assert summary["accel_cells"] == 0
+    reason = summary["accel_fallbacks"]["scheduler.policy=fifo"]
+    assert reason.startswith("accel batch failed: CellBatchNotConverged")
+
+
+def test_attempt_process_pins_cpu_backend(monkeypatch):
+    """The spawned attempt entry point pins JAX to the CPU before it
+    computes anything, so a parent that owns the chip keeps it."""
+    import multiprocessing
+
+    import jax
+
+    from repro.scenarios.worker import _cell_worker
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    before = jax.config.jax_platforms
+    jax.config.update("jax_platforms", None)
+    recv, send = multiprocessing.Pipe(duplex=False)
+    try:
+        spec = _fifo_sweep().expand()[0][1]
+        _cell_worker(send, "c", spec.to_dict())
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+        assert jax.config.jax_platforms == "cpu"
+        kind, report = recv.recv()
+        assert kind == "ok" and report["jobs_completed"] == 30
+    finally:
+        jax.config.update("jax_platforms", before)
+        recv.close()
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    """The entry points' compile cache: ``JAX_COMPILATION_CACHE_DIR``
+    when set (and nothing else touched), else the checkout's fixed,
+    git-ignored ``.jax_cache``."""
+    import jax
+
+    from repro.utils.jax_cache import CHECKOUT_CACHE, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(CHECKOUT_CACHE)
+        assert jax.config.jax_compilation_cache_dir == str(CHECKOUT_CACHE)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = CHECKOUT_CACHE.parent
+    assert (root / "src" / "repro").is_dir()
+    ignored = (root / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored
+
+
+def test_compile_cache_without_jax(monkeypatch):
+    """jax is optional: without it the helper does nothing and returns
+    None, so the entry points that call it still run."""
+    import sys
+
+    from repro.utils.jax_cache import enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setitem(sys.modules, "jax", None)
+    assert enable_compile_cache() is None
