@@ -78,6 +78,8 @@ class Master:
         self.tags: dict[str, object] = {}
         self._waiters: dict[int, list[asyncio.Future]] = {}
         self._server: asyncio.AbstractServer | None = None
+        #: Writers of the open worker and client connections.
+        self._conns: set[asyncio.StreamWriter] = set()
         self._pacer: asyncio.Task | None = None
         self._stopping = asyncio.Event()
         self.port: int | None = None
@@ -199,6 +201,11 @@ class Master:
                 w.sender.cancel()
         if self._server is not None:
             self._server.close()
+            # wait_closed() waits for every accepted connection to close
+            # (Python >= 3.12.1): close them, or connected workers and
+            # clients hold stop() forever.
+            for writer in self._conns:
+                writer.close()
             await self._server.wait_closed()
         self.checkpoint()
         self.engine.journal.close()
@@ -249,6 +256,7 @@ class Master:
 
     # -- connections -----------------------------------------------------
     async def _handle(self, reader, writer) -> None:
+        self._conns.add(writer)
         try:
             while True:
                 msg = await protocol.recv(reader)
@@ -264,6 +272,7 @@ class Master:
         except (ConnectionError, asyncio.CancelledError):
             pass
         finally:
+            self._conns.discard(writer)
             writer.close()
 
     async def _dispatch(self, op: str, msg: dict, writer) -> dict | None:

@@ -13,20 +13,47 @@ os.environ.setdefault(
     "--xla_cpu_multi_thread_eigen=false intra_op_parallelism_threads=1",
 )
 
+import signal  # noqa: E402
+import threading  # noqa: E402
+
 import pytest  # noqa: E402
 
+#: Per-test wall-clock limit (seconds); ``@pytest.mark.timeout(n)`` sets
+#: a test's own.  The fault-injection and sweep suites spawn worker
+#: processes and the service suite runs live sockets: a hang should fail
+#: its one test, not wedge the whole run.
+TEST_LIMIT_S = 300.0
 
-def pytest_collection_modifyitems(config, items):
-    # Per-test wall-clock budget: the fault-injection and self-healing
-    # sweep suites spawn worker processes, and a hung child should fail
-    # its one test, not wedge the whole run.  Gated on the optional
-    # pytest-timeout plugin (requirements-dev.txt) being installed —
-    # without it the marker would be inert noise.
-    if not config.pluginmanager.hasplugin("timeout"):
+
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "timeout(seconds): this test's wall-clock limit"
+    )
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    # SIGALRM interrupts the main thread only; elsewhere (no setitimer,
+    # or a test running off the main thread) there is no limit.
+    if (
+        not hasattr(signal, "setitimer")
+        or threading.current_thread() is not threading.main_thread()
+    ):
+        yield
         return
-    for item in items:
-        if item.get_closest_marker("timeout") is None:
-            item.add_marker(pytest.mark.timeout(300))
+    marker = item.get_closest_marker("timeout")
+    limit = float(marker.args[0]) if marker and marker.args else TEST_LIMIT_S
+
+    def expired(signum, frame):
+        pytest.fail(f"test exceeded its {limit:g} s time limit")
+
+    previous = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
 
 
 @pytest.fixture(scope="session")

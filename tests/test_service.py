@@ -41,6 +41,7 @@ from repro.service import (
     read_journal,
     replay_journal,
 )
+from repro.service.master import CHECKPOINT_KIND
 from repro.service.protocol import ServiceClient
 
 REPO = Path(__file__).resolve().parent.parent
@@ -90,6 +91,12 @@ def client_submit(port: int, jobs: list[dict], user="u0") -> list[int]:
             assert r["ok"], r
             out.append(r["job_id"])
         return out
+
+
+def run_session(coro, limit: float = 60.0):
+    """Run one live session under a wall-clock limit: a lost wake-up
+    fails its own test with a TimeoutError instead of hanging the run."""
+    return asyncio.run(asyncio.wait_for(coro, limit))
 
 
 async def drain(engine, n, timeout=20.0):
@@ -143,7 +150,7 @@ def test_live_session_replays_bit_identically(tmp_path, policy):
             await wk.die()
         return fp_live, completions
 
-    fp_live, completions = asyncio.run(session())
+    fp_live, completions = run_session(session())
     assert len(completions) == 16
 
     twin = replay_journal(tmp_path / "live.jsonl")
@@ -170,11 +177,48 @@ def test_journal_doubles_as_plain_trace(tmp_path):
         for w in workers:
             await w.die()
 
-    asyncio.run(session())
+    run_session(session())
     jobs, _, meta = load_trace(tmp_path / "live.jsonl")
     assert len(jobs) == 5
     assert meta["journal"] is True
     assert [j.job_id for j in jobs] == sorted(j.job_id for j in jobs)
+
+
+def test_stop_closes_open_connections(tmp_path):
+    """stop() returns while workers and a client are still connected:
+    it closes every connection the server accepted, then writes the
+    checkpoint and closes the journal."""
+    ck = tmp_path / "ck.json"
+
+    async def session():
+        # Only stop() writes the checkpoint: the pacer's period is long.
+        engine, master, workers = await boot(
+            tmp_path, "fifo", checkpoint_path=str(ck),
+            checkpoint_every_wall=3600.0,
+        )
+        while len(master.workers) < CLUSTER["num_machines"]:
+            await asyncio.sleep(0.01)
+        loop = asyncio.get_running_loop()
+        client = await loop.run_in_executor(
+            None, ServiceClient, "127.0.0.1", master.port
+        )
+        try:
+            r = await loop.run_in_executor(None, client.call, {"op": "status"})
+            assert r["workers"] == {"0": {"alive": True}, "1": {"alive": True}}
+            assert not ck.exists()
+            await master.stop()
+            # The master closed the client's connection: EOF, no reply.
+            eof = await loop.run_in_executor(None, client.read_line)
+        finally:
+            client.close()
+            for w in workers:
+                await w.die()
+        return engine, eof
+
+    engine, eof = run_session(session(), limit=5.0)
+    assert eof is None
+    assert engine.journal._f.closed
+    assert json.loads(ck.read_text())["kind"] == CHECKPOINT_KIND
 
 
 # ---------------------------------------------------------------------------
@@ -210,7 +254,7 @@ def test_crash_restore_randomized_kill_point(tmp_path, seed):
         engine.journal._f.close()
         return ids
 
-    ids_before = asyncio.run(phase_one())
+    ids_before = run_session(phase_one())
 
     # Torn-tail realism: append half a line, as a crash mid-append would.
     with open(tmp_path / "live.jsonl", "a") as f:
@@ -257,7 +301,7 @@ def test_crash_restore_randomized_kill_point(tmp_path, seed):
             await w.die()
         return redone, fresh, fp, completions
 
-    redone, fresh, fp, completions = asyncio.run(phase_two())
+    redone, fresh, fp, completions = run_session(phase_two())
     # Exactly-once: pre-crash tags resolve to the original ids, fresh
     # jobs get new ids, and the union is exactly n_total distinct jobs.
     assert redone == ids_before
@@ -454,7 +498,7 @@ def test_master_backpressure_queues_then_drains(tmp_path):
         await master.stop()
         return fp
 
-    fp = asyncio.run(session())
+    fp = run_session(session())
     twin = replay_journal(tmp_path / "live.jsonl")
     assert live_fingerprint(twin) == fp
 
@@ -483,7 +527,7 @@ def test_telemetry_snapshot_vocabulary(tmp_path):
             await w.die()
         return snap
 
-    snap = asyncio.run(session())
+    snap = run_session(session())
     assert snap["jobs"]["completed"] == 8
     assert snap["jobs"]["submitted"] == 8
     for block, keys in [
