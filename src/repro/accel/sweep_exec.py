@@ -17,7 +17,9 @@ different answer for specs it can't represent.
 
 from __future__ import annotations
 
+import contextvars
 import time
+from contextlib import contextmanager
 
 import numpy as np
 
@@ -64,19 +66,51 @@ def _resolve_eps(spec: ScenarioSpec, arrivals: list[float]) -> float:
     return float(eps)
 
 
-def synthesize_cell(spec: ScenarioSpec) -> dict:
-    """Materialize one eligible spec into flat cell params (numpy).
+class WorkloadMemo:
+    """The workload parts built inside one :func:`workload_memo` scope,
+    with counts of builds and of calls answered from the memo."""
 
-    Reuses the runner's ``build_workload``/``build_cluster`` so the job
-    stream is bit-identical to the Python cell's; raises ``ValueError``
-    if the materialized workload violates the fluid contract (unequal
-    per-phase durations, non-uniform weights) — callers treat that as
-    ineligibility and fall back.
+    def __init__(self) -> None:
+        self.parts: dict[tuple, dict | ValueError] = {}
+        self.built = 0
+        self.hits = 0
+
+
+_MEMO: contextvars.ContextVar[WorkloadMemo | None] = contextvars.ContextVar(
+    "repro_accel_workload_memo", default=None
+)
+
+
+@contextmanager
+def workload_memo():
+    """Share workload parts among the :func:`synthesize_cell` calls of
+    one claim.
+
+    Inside the scope each distinct workload (``spec.workload`` and the
+    resolved host count) is built once; every other cell on it, and the
+    batch's second synthesis after the eligibility dry run, reuses the
+    read-only arrays.  Entering while a scope is open joins that scope,
+    so one claim shares one memo.  The memo dies with the outermost
+    scope; yields the :class:`WorkloadMemo` with its counts.
     """
-    from repro.scenarios.runner import build_cluster, build_workload
+    memo = _MEMO.get()
+    if memo is not None:
+        yield memo
+        return
+    memo = WorkloadMemo()
+    token = _MEMO.set(memo)
+    try:
+        yield memo
+    finally:
+        _MEMO.reset(token)
+
+
+def _build_workload_part(spec: ScenarioSpec) -> dict:
+    """The per-job arrays of the spec's workload, read-only; raises
+    ``ValueError`` if the job stream violates the fluid contract."""
+    from repro.scenarios.runner import build_workload
 
     jobs, _ = build_workload(spec)
-    cluster = build_cluster(spec)
     j = len(jobs)
 
     def phase_arrays(tasks_of):
@@ -103,7 +137,62 @@ def synthesize_cell(spec: ScenarioSpec) -> dict:
                          "keys ignore weight")
     if any(job.reduce_slowstart != 1.0 for job in jobs):
         raise ValueError("reduce_slowstart != 1.0")
-    arrival = np.array([job.arrival_time for job in jobs], np.float64)
+    part = {
+        "arrival": np.array([job.arrival_time for job in jobs], np.float64),
+        "m_work": m_n * m_d,
+        "m_dur": m_d,
+        "m_tasks": m_n,
+        "r_work": r_n * r_d,
+        "r_dur": r_d,
+        "r_tasks": r_n,
+        "weight": weight,
+        "present": np.ones(j, np.float64),
+    }
+    for arr in part.values():
+        arr.setflags(write=False)
+    return part
+
+
+def _workload_part(spec: ScenarioSpec) -> dict:
+    """The workload part of a cell, from the open memo when there is one.
+    A ``ValueError`` is memoized too: every cell of an unfit workload
+    gets the same rejection without a rebuild."""
+    memo = _MEMO.get()
+    if memo is None:
+        return _build_workload_part(spec)
+    w = spec.workload
+    key = (w, w.num_hosts or spec.cluster.num_machines)
+    part = memo.parts.get(key)
+    if part is None:
+        memo.built += 1
+        try:
+            part = _build_workload_part(spec)
+        except ValueError as e:
+            part = e
+        memo.parts[key] = part
+    else:
+        memo.hits += 1
+    if isinstance(part, ValueError):
+        raise ValueError(*part.args)
+    return part
+
+
+def synthesize_cell(spec: ScenarioSpec) -> dict:
+    """Materialize one eligible spec into flat cell params (numpy).
+
+    Reuses the runner's ``build_workload``/``build_cluster`` so the job
+    stream is bit-identical to the Python cell's; raises ``ValueError``
+    if the materialized workload violates the fluid contract (unequal
+    per-phase durations, non-uniform weights) — callers treat that as
+    ineligibility and fall back.  Inside a :func:`workload_memo` scope
+    the workload part is built once per distinct workload; its arrays
+    are read-only in every case.
+    """
+    from repro.scenarios.runner import build_cluster
+
+    part = _workload_part(spec)
+    cluster = build_cluster(spec)
+    m_work, r_work = part["m_work"], part["r_work"]
 
     alpha = spec.scheduler.error_alpha
     if alpha != 0.0:
@@ -113,26 +202,17 @@ def synthesize_cell(spec: ScenarioSpec) -> dict:
         # order, so alpha>0 cells agree in distribution, not
         # draw-for-draw; docs/accel.md#fidelity).
         rng = np.random.default_rng(spec.scheduler.error_seed)
-        factors = rng.uniform(1.0 - alpha, 1.0 + alpha, size=(j, 2))
-        est_m = np.maximum(m_n * m_d * factors[:, 0], 1e-9)
-        est_r = np.maximum(r_n * r_d * factors[:, 1], 1e-9)
+        factors = rng.uniform(1.0 - alpha, 1.0 + alpha, size=(len(m_work), 2))
+        est_m = np.maximum(m_work * factors[:, 0], 1e-9)
+        est_r = np.maximum(r_work * factors[:, 1], 1e-9)
     else:
-        est_m = m_n * m_d
-        est_r = r_n * r_d
+        est_m, est_r = m_work, r_work
 
     policy = spec.scheduler.policy
     return {
-        "arrival": arrival,
-        "m_work": m_n * m_d,
-        "m_dur": m_d,
-        "m_tasks": m_n,
-        "r_work": r_n * r_d,
-        "r_dur": r_d,
-        "r_tasks": r_n,
+        **part,
         "est_m": est_m,
         "est_r": est_r,
-        "weight": weight,
-        "present": np.ones(j, np.float64),
         "policy": np.int32(POLICY_CODES[policy]),
         "map_slots": np.float64(
             cluster.num_machines * cluster.map_slots_per_machine
@@ -147,7 +227,7 @@ def synthesize_cell(spec: ScenarioSpec) -> dict:
         "dt_cap": np.float64(
             np.inf if policy == "fifo" else spec.heartbeat
         ),
-        "eps": np.float64(_resolve_eps(spec, [jb.arrival_time for jb in jobs])),
+        "eps": np.float64(_resolve_eps(spec, part["arrival"].tolist())),
     }
 
 
@@ -165,7 +245,8 @@ def run_cells_accel(
 
     ``wall_s`` is the batch wall divided evenly across cells — the cells
     ran concurrently in one program, so per-cell wall is an accounting
-    convention, not a measurement.
+    convention, not a measurement.  Opens a :func:`workload_memo` scope
+    unless the caller has one open, so cells on one workload share it.
     """
     if not specs:
         return []
@@ -179,29 +260,30 @@ def run_cells_accel(
         return "hfsp" if spec.scheduler.policy == "hfsp" else "plain"
 
     out: list[dict | None] = [None] * len(specs)
-    for klass in ("plain", "hfsp"):
-        idx = [i for i, s in enumerate(specs) if _klass(s) == klass]
-        if not idx:
-            continue
-        t0 = time.perf_counter()
-        cells = [synthesize_cell(specs[i]) for i in idx]
-        packed = pack_cells(cells)
-        final = run_packed(
-            packed,
-            t_chunk=t_chunk,
-            max_chunks=max_chunks,
-            impl=impl if klass == "hfsp" else "none",
-            on_chunk=on_chunk,
-        )
-        wall = time.perf_counter() - t0
-        for i, cell, summ in zip(idx, cells, summarize(packed, final)):
-            out[i] = {
-                "spec": specs[i].to_dict(),
-                "wall_s": round(wall / len(idx), 3),
-                "compute": "accel",
-                "event_epsilon_resolved": float(cell["eps"]),
-                **summ,
-            }
+    with workload_memo():
+        for klass in ("plain", "hfsp"):
+            idx = [i for i, s in enumerate(specs) if _klass(s) == klass]
+            if not idx:
+                continue
+            t0 = time.perf_counter()
+            cells = [synthesize_cell(specs[i]) for i in idx]
+            packed = pack_cells(cells)
+            final = run_packed(
+                packed,
+                t_chunk=t_chunk,
+                max_chunks=max_chunks,
+                impl=impl if klass == "hfsp" else "none",
+                on_chunk=on_chunk,
+            )
+            wall = time.perf_counter() - t0
+            for i, cell, summ in zip(idx, cells, summarize(packed, final)):
+                out[i] = {
+                    "spec": specs[i].to_dict(),
+                    "wall_s": round(wall / len(idx), 3),
+                    "compute": "accel",
+                    "event_epsilon_resolved": float(cell["eps"]),
+                    **summ,
+                }
     return out
 
 
@@ -221,28 +303,29 @@ def run_sweep_accel(
     cells = sweep.expand()
     eligible: list[tuple[str, ScenarioSpec]] = []
     results: dict[str, dict] = {}
-    for cid, spec in cells:
-        ok, _reason = accel_eligible(spec)
-        if ok:
-            try:
-                synthesize_cell(spec)
-            except ValueError:
-                ok = False
-        if ok:
-            eligible.append((cid, spec))
-        else:
-            results[cid] = run_scenario(spec)
-            if progress:
-                progress(cid, results[cid])
-    if eligible:
-        batch = run_cells_accel(
-            [s for _, s in eligible],
-            impl=impl,
-            t_chunk=t_chunk,
-            max_chunks=max_chunks,
-        )
-        for (cid, _), res in zip(eligible, batch):
-            results[cid] = res
-            if progress:
-                progress(cid, res)
+    with workload_memo():
+        for cid, spec in cells:
+            ok, _reason = accel_eligible(spec)
+            if ok:
+                try:
+                    synthesize_cell(spec)
+                except ValueError:
+                    ok = False
+            if ok:
+                eligible.append((cid, spec))
+            else:
+                results[cid] = run_scenario(spec)
+                if progress:
+                    progress(cid, results[cid])
+        if eligible:
+            batch = run_cells_accel(
+                [s for _, s in eligible],
+                impl=impl,
+                t_chunk=t_chunk,
+                max_chunks=max_chunks,
+            )
+            for (cid, _), res in zip(eligible, batch):
+                results[cid] = res
+                if progress:
+                    progress(cid, res)
     return results

@@ -200,7 +200,8 @@ def _compute_with_retries(
 def _accel_fit(spec: ScenarioSpec) -> tuple[bool, str]:
     """Full accel-eligibility check for one cell: the spec-level gate
     plus a synthesis dry-run (a generator workload can still violate the
-    fluid contract — unequal durations, non-uniform weights)."""
+    fluid contract — unequal durations, non-uniform weights).  Under an
+    open workload memo the batch reuses the dry run's build."""
     from repro.accel.sweep_exec import accel_eligible, synthesize_cell
 
     ok, reason = accel_eligible(spec)
@@ -251,8 +252,12 @@ def run_worker(
     represent — fault injection, trace workloads, non-fluid generators —
     fall back to the unchanged per-cell spawned Python path, so a mixed
     sweep still converges; the fallback reasons are reported in the
-    summary's ``"accel_fallbacks"``.  A batch that exhausts its chunk
-    budget (:class:`~repro.accel.cell_step.CellBatchNotConverged`)
+    summary's ``"accel_fallbacks"``.  Each claim synthesizes under one
+    workload memo (:func:`~repro.accel.sweep_exec.workload_memo`):
+    ``"accel_workloads_built"`` counts the workloads built and
+    ``"accel_synth_hits"`` the syntheses answered from the memo.  A batch
+    that exhausts its chunk budget
+    (:class:`~repro.accel.cell_step.CellBatchNotConverged`)
     demotes its cells to the Python path rather than quarantining them;
     any other accel error — a device or compile fault — fails the
     worker, so a broken device path cannot pass as a Python sweep.
@@ -279,6 +284,8 @@ def run_worker(
     if compute == "accel":
         summary["accel_cells"] = 0
         summary["accel_batches"] = 0
+        summary["accel_workloads_built"] = 0
+        summary["accel_synth_hits"] = 0
         summary["accel_fallbacks"] = accel_unfit
 
     def _finish_one(cid: str, result: dict) -> None:
@@ -290,7 +297,19 @@ def run_worker(
             progress(cid, result)
 
     def _try_accel_batch(todo: list[str], held, now: float) -> bool:
-        """Claim + run one vmapped batch; True if any cell was computed."""
+        """Claim + run one vmapped batch; True if any cell was computed.
+        The eligibility dry run and the batch share one workload memo,
+        so each distinct workload of the claim is built once."""
+        from repro.accel.sweep_exec import workload_memo
+
+        with workload_memo() as memo:
+            try:
+                return _claim_and_run(todo, held, now)
+            finally:
+                summary["accel_workloads_built"] += memo.built
+                summary["accel_synth_hits"] += memo.hits
+
+    def _claim_and_run(todo: list[str], held, now: float) -> bool:
         from repro.accel.cell_step import CellBatchNotConverged
         from repro.accel.sweep_exec import run_cells_accel
 

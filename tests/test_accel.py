@@ -403,3 +403,119 @@ def test_compile_cache_without_jax(monkeypatch):
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setitem(sys.modules, "jax", None)
     assert enable_compile_cache() is None
+
+
+# ---------------------------------------------------------------------------
+# Workload memo: each distinct workload built once per claim
+# ---------------------------------------------------------------------------
+def _cell_bytes(cell: dict) -> dict:
+    return {k: (np.asarray(v).dtype, np.asarray(v).tobytes())
+            for k, v in cell.items()}
+
+
+@pytest.mark.parametrize("policy", ACCEL_POLICIES)
+@pytest.mark.parametrize("kind", ("fb", "fb_scaled"))
+def test_memoized_synthesis_is_byte_identical(kind, policy):
+    """Cells answered from the memo carry every key's exact bytes; the
+    per-cell part (error model, heartbeat cap, event window) is computed
+    afresh for each cell on the shared workload."""
+    from repro.accel.sweep_exec import workload_memo
+
+    base = paper_fb_base(seed=3).quick().override(**{
+        "workload.kind": kind, "scheduler.policy": policy,
+        "scheduler.error_seed": 17,
+    })
+    specs = [
+        base.override(**{"scheduler.error_alpha": alpha,
+                         "heartbeat": heartbeat, "event_epsilon": eps})
+        for alpha in (0.0, 0.3)
+        for heartbeat in (3.0, 0.5)
+        for eps in ("auto", 1.5)
+    ]
+    plain = [_cell_bytes(synthesize_cell(s)) for s in specs]
+    with workload_memo() as memo:
+        memoized = [_cell_bytes(synthesize_cell(s)) for s in specs]
+    assert memoized == plain
+    assert (memo.built, memo.hits) == (1, len(specs) - 1)
+
+
+@pytest.mark.parametrize(
+    "seeds, policies, built, hits",
+    [((0, 1), ACCEL_POLICIES, 2, 14), (tuple(range(8)), ("hfsp",), 8, 8)],
+    ids=["2-seeds-x-4-policies", "8-distinct-seeds"],
+)
+def test_worker_builds_each_workload_once_per_claim(
+        tmp_path, seeds, policies, built, hits):
+    """The dry run and the batch of one claim share the memo: one build
+    per distinct workload, every other synthesis a hit."""
+    from repro.scenarios.worker import run_worker
+
+    sweep = SweepSpec(
+        name="memo",
+        base=paper_fb_base().quick(),
+        grids=(SweepSpec.grid(**{"workload.seed": seeds,
+                                 "scheduler.policy": policies}),),
+    )
+    summary = run_worker(sweep, tmp_path / "s.jsonl", compute="accel",
+                         deadline=300.0)
+    assert summary["accel_cells"] == len(seeds) * len(policies)
+    assert summary["accel_batches"] == 1
+    assert summary["accel_workloads_built"] == built
+    assert summary["accel_synth_hits"] == hits
+
+
+@pytest.mark.parametrize("kind", ("fb", "fb_scaled"))
+def test_unfit_workload_rejects_every_cell_from_one_build(kind):
+    from repro.accel.sweep_exec import workload_memo
+
+    base = paper_fb_base(seed=0).quick().override(**{
+        "workload.kind": kind, "workload.task_jitter": 0.3,
+    })
+    with workload_memo() as memo:
+        for policy in ACCEL_POLICIES:
+            spec = base.override(**{"scheduler.policy": policy})
+            with pytest.raises(ValueError, match="unequal per-phase"):
+                synthesize_cell(spec)
+    assert (memo.built, memo.hits) == (1, len(ACCEL_POLICIES) - 1)
+
+
+@pytest.mark.parametrize("scoped", (False, True), ids=["no-scope", "scope"])
+def test_workload_arrays_are_read_only(scoped):
+    """Cells share the workload's arrays, so a write into one raises
+    instead of reaching the others."""
+    import contextlib
+
+    from repro.accel.sweep_exec import workload_memo
+
+    spec = paper_fb_base(seed=0).quick()
+    with workload_memo() if scoped else contextlib.nullcontext():
+        cell = synthesize_cell(spec)
+    for key in ("arrival", "m_work", "m_dur", "m_tasks", "r_work", "r_dur",
+                "r_tasks", "weight", "present", "est_m", "est_r"):
+        assert not cell[key].flags.writeable, key
+        with pytest.raises(ValueError, match="read-only"):
+            cell[key][0] = 1.0
+
+
+@pytest.mark.parametrize("nested", (False, True),
+                         ids=["second-scope", "nested-scope"])
+def test_memo_lives_with_its_scope(nested):
+    """A second scope builds again; a scope opened inside an open one
+    joins it."""
+    from repro.accel.sweep_exec import workload_memo
+
+    spec = paper_fb_base(seed=0).quick()
+    with workload_memo() as first:
+        a = synthesize_cell(spec)
+        if nested:
+            with workload_memo() as inner:
+                b = synthesize_cell(spec)
+            assert inner is first
+    if not nested:
+        with workload_memo() as second:
+            b = synthesize_cell(spec)
+        assert (second.built, second.hits) == (1, 0)
+        assert b["arrival"] is not a["arrival"]
+    else:
+        assert (first.built, first.hits) == (1, 1)
+        assert b["arrival"] is a["arrival"]
