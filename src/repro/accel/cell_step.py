@@ -50,6 +50,7 @@ only; see docs/accel.md.
 
 from __future__ import annotations
 
+import collections
 import functools
 
 import numpy as np
@@ -66,8 +67,49 @@ _SNAP = 1e-6
 _PARAM_KEYS = (
     "arrival", "m_work", "m_dur", "m_tasks", "r_work", "r_dur",
     "r_tasks", "est_m", "est_r", "weight", "present",
-    "policy", "map_slots", "red_slots", "dt_cap", "eps",
+    "policy", "map_slots", "red_slots", "dt_cap", "eps", "close",
 )
+
+
+#: Records :class:`RunLog` keeps: far more than the calls of one claim
+#: (two) or of one benchmark window (two per matrix).
+_RUN_LOG_KEEP = 1024
+
+
+class RunLog:
+    """Counters of the latest :func:`run_packed` calls, oldest first.
+
+    One record per call: ``rows`` (cells in the batch), ``t_chunk``,
+    ``host_syncs`` (chunks run; the host syncs once after each),
+    ``event_steps`` (the cells' ``steps`` summed), ``scanned_row_steps``
+    (rows x ``t_chunk`` x chunks: the steps the device scanned, done
+    cells' no-op steps included) and ``window_closes`` (steps that
+    ended on an arrival-window close).  ``count`` is the number of calls
+    ever logged, so :meth:`since` can hand a caller exactly the calls
+    made after it looked.  It keeps the last :data:`_RUN_LOG_KEEP`."""
+
+    def __init__(self):
+        self.records: collections.deque[dict] = collections.deque(
+            maxlen=_RUN_LOG_KEEP)
+        self.count = 0
+
+    def append(self, record: dict) -> None:
+        self.records.append(record)
+        self.count += 1
+
+    def since(self, count: int) -> list[dict]:
+        """The records of the calls logged after ``count`` calls."""
+        n = self.count - count
+        if n > len(self.records):
+            raise ValueError(f"{n} calls since {count}; the log keeps "
+                             f"{len(self.records)}")
+        return list(self.records)[len(self.records) - n:]
+
+
+#: The process's log.  The sweep worker sums a claim's calls from it, and
+#: the benchmark's readers take the calls of their window; neither can
+#: hand ``run_packed`` a log of its own through the served path.
+RUN_LOG = RunLog()
 
 
 class CellBatchNotConverged(RuntimeError):
@@ -127,22 +169,31 @@ def init_state(params: dict) -> dict:
         "comp": np.zeros_like(m_work),
         "done": np.asarray(params["present"], np.float64) < 0.5,
         "steps": np.zeros(b, np.int64),
+        "closes": np.zeros(b, np.int64),
     }
 
 
 def pack_cells(cells: list[dict], width: int | None = None) -> dict:
     """Stack per-cell param dicts (flat ``(J_i,)`` arrays + scalars)
     into one ``(B, J)`` batch, padding every cell to a common bucket
-    width.  Padding rows: ``present=0``, ``arrival=inf``, durations 1
-    (any positive value — they never activate)."""
+    width.  Padding rows: ``present=0``, ``arrival=close=inf``,
+    durations 1 (any positive value — they never activate).
+
+    Each job's arrival-window close ``arrival + eps`` is computed here,
+    once, in IEEE float64, and carried as a row: a step that closes a
+    window lands on this number itself (see :func:`_cell_step`)."""
     jmax = max(len(c["arrival"]) for c in cells)
     width = _bucket(jmax) if width is None else width
     if width < jmax:
         raise ValueError(f"width {width} < widest cell {jmax}")
     out: dict = {}
-    fills = {"arrival": np.inf, "m_dur": 1.0, "r_dur": 1.0}
+    fills = {"arrival": np.inf, "close": np.inf, "m_dur": 1.0, "r_dur": 1.0}
     for key in _PARAM_KEYS:
-        vals = [np.asarray(c[key]) for c in cells]
+        if key == "close":
+            vals = [np.asarray(c["arrival"], np.float64)
+                    + np.float64(c["eps"]) for c in cells]
+        else:
+            vals = [np.asarray(c[key]) for c in cells]
         if vals[0].ndim == 0:
             dt = np.int32 if key == "policy" else np.float64
             out[key] = np.stack(vals).astype(dt)
@@ -178,7 +229,18 @@ def _greedy_alloc(jnp, key, caps, slots):
 
 def _cell_step(params, state, water_fill):
     """Advance one cell by one event window.  Pure, fixed-shape; exact
-    no-op (bitwise) once the cell is done."""
+    no-op (bitwise) once the cell is done.
+
+    A step that ends at an arrival-window close lands on the packed
+    ``close`` row itself (the host's ``arrival + eps``), not on
+    ``t + dt``.  With the ``"auto"`` window ``eps`` is a gap between two
+    consecutive arrivals, so one window closes exactly on the next
+    job's arrival, and ``arrival <= t`` decides that job at equality.
+    In IEEE float64 ``t + (close - t)`` is ``close`` whenever
+    ``t >= close / 2`` (the subtraction is exact), but a TPU computes
+    float64 as f32 pairs, whose ``t + dt`` can land 1e-10 short: the
+    job would then wait a whole further window.  Taking the close value
+    keeps the comparison one between two copies of the same number."""
     _, jnp, _ = _require()
     t = state["t"]
     present = params["present"] > 0.5
@@ -259,9 +321,11 @@ def _cell_step(params, state, water_fill):
     r_alloc = _greedy_alloc(jnp, key_r, r_cap, params["red_slots"])
 
     # --- dt: next arrival-window close, phase completion, or heartbeat.
+    # ``close`` is monotone in ``arrival``: the least pending close is
+    # that of the earliest pending arrival.
     pending = present & ~arrived
-    next_arr = jnp.min(jnp.where(pending, params["arrival"], jnp.inf))
-    dt_arr = next_arr + params["eps"] - t  # window closes eps after open
+    next_close = jnp.min(jnp.where(pending, params["close"], jnp.inf))
+    dt_arr = next_close - t
     m_fin = jnp.where(m_alloc > 0.0, state["m_rem"] / m_alloc, jnp.inf)
     r_fin = jnp.where(r_alloc > 0.0, state["r_rem"] / r_alloc, jnp.inf)
     # The heartbeat cap exists to re-rank while jobs *compete*; with at
@@ -270,15 +334,14 @@ def _cell_step(params, state, water_fill):
     # of an fb trace is ~40% of its steps otherwise).
     n_live = jnp.sum(live.astype(t.dtype))
     dt_cap = jnp.where(n_live > 1.0, params["dt_cap"], jnp.inf)
-    dt = jnp.minimum(
-        jnp.minimum(dt_arr, dt_cap),
-        jnp.minimum(jnp.min(m_fin), jnp.min(r_fin)),
-    )
+    dt_other = jnp.minimum(dt_cap, jnp.minimum(jnp.min(m_fin), jnp.min(r_fin)))
+    dt = jnp.minimum(dt_arr, dt_other)
     go = jnp.any(live) | jnp.any(pending)
     dt = jnp.where(jnp.isfinite(dt), dt, 0.0)
+    closes = jnp.isfinite(dt_arr) & (dt_arr <= dt_other)
 
     # --- integrate ---
-    t2 = t + dt
+    t2 = jnp.where(closes, next_close, t + dt)
     m_rem2 = state["m_rem"] - m_alloc * dt
     m_rem2 = jnp.where(m_rem2 <= _SNAP, 0.0, m_rem2)
     r_rem2 = state["r_rem"] - r_alloc * dt
@@ -303,6 +366,8 @@ def _cell_step(params, state, water_fill):
         ),
         "done": sel(state["done"] | newly_done, state["done"]),
         "steps": state["steps"] + go.astype(state["steps"].dtype),
+        "closes": state["closes"]
+        + (go & closes).astype(state["closes"].dtype),
     }
 
 
@@ -358,30 +423,41 @@ def run_packed(
     runs between chunks — the distributed-sweep worker renews its
     leases there.  Raises if ``max_chunks`` scans don't drain the batch
     (a liveness bug, not a tuning knob — every real cell terminates):
-    :class:`CellBatchNotConverged`."""
+    :class:`CellBatchNotConverged`.  Every call appends its counters to
+    :data:`RUN_LOG`, one that does not drain included."""
     jax, jnp, _ = _require()
     width = int(np.asarray(params["arrival"]).shape[-1])
     runner = _chunk_runner(width, int(t_chunk), impl)
     state = init_state(params)
     b = state["t"].shape[0]
+    chunks, drained = 0, False
     with jax.enable_x64(True):
         params_j = {k: jnp.asarray(v) for k, v in params.items()}
         state = {k: jnp.asarray(v) for k, v in state.items()}
-        for chunk in range(max_chunks):
+        while chunks < max_chunks and not drained:
             state = runner(params_j, state)
+            chunks += 1
             cell_done = np.asarray(
                 jnp.all(state["done"] | (params_j["present"] < 0.5), axis=-1)
             )
             if on_chunk is not None:
-                on_chunk(chunk + 1, int(cell_done.sum()), b)
-            if cell_done.all():
-                break
-        else:
-            raise CellBatchNotConverged(
-                f"accel cell batch did not converge in {max_chunks} "
-                f"chunks of {t_chunk} steps"
-            )
-    return {k: np.asarray(v) for k, v in state.items()}
+                on_chunk(chunks, int(cell_done.sum()), b)
+            drained = bool(cell_done.all())
+    final = {k: np.asarray(v) for k, v in state.items()}
+    RUN_LOG.append({
+        "rows": b,
+        "t_chunk": int(t_chunk),
+        "host_syncs": chunks,
+        "event_steps": int(final["steps"].sum()),
+        "scanned_row_steps": b * int(t_chunk) * chunks,
+        "window_closes": int(final["closes"].sum()),
+    })
+    if not drained:
+        raise CellBatchNotConverged(
+            f"accel cell batch did not converge in {max_chunks} "
+            f"chunks of {t_chunk} steps"
+        )
+    return final
 
 
 def summarize(params: dict, final: dict) -> list[dict]:

@@ -197,6 +197,15 @@ def _compute_with_retries(
             time.sleep(min(0.05, max(0.0, deadline - time.monotonic())))
 
 
+#: ``RUN_LOG`` record field -> the worker summary's key.
+_RUN_COUNTERS = {
+    "event_steps": "accel_event_steps",
+    "scanned_row_steps": "accel_scanned_row_steps",
+    "window_closes": "accel_window_closes",
+    "host_syncs": "accel_host_syncs",
+}
+
+
 def _accel_fit(spec: ScenarioSpec) -> tuple[bool, str]:
     """Full accel-eligibility check for one cell: the spec-level gate
     plus a synthesis dry-run (a generator workload can still violate the
@@ -255,7 +264,11 @@ def run_worker(
     summary's ``"accel_fallbacks"``.  Each claim synthesizes under one
     workload memo (:func:`~repro.accel.sweep_exec.workload_memo`):
     ``"accel_workloads_built"`` counts the workloads built and
-    ``"accel_synth_hits"`` the syntheses answered from the memo.  A batch
+    ``"accel_synth_hits"`` the syntheses answered from the memo.  The
+    device program's counters of the claims' ``run_packed`` calls
+    (:data:`~repro.accel.cell_step.RUN_LOG`) are summed beside them:
+    ``"accel_event_steps"``, ``"accel_scanned_row_steps"``,
+    ``"accel_window_closes"`` and ``"accel_host_syncs"``.  A batch
     that exhausts its chunk budget
     (:class:`~repro.accel.cell_step.CellBatchNotConverged`)
     demotes its cells to the Python path rather than quarantining them;
@@ -286,6 +299,8 @@ def run_worker(
         summary["accel_batches"] = 0
         summary["accel_workloads_built"] = 0
         summary["accel_synth_hits"] = 0
+        for key in _RUN_COUNTERS.values():
+            summary[key] = 0
         summary["accel_fallbacks"] = accel_unfit
 
     def _finish_one(cid: str, result: dict) -> None:
@@ -300,14 +315,19 @@ def run_worker(
         """Claim + run one vmapped batch; True if any cell was computed.
         The eligibility dry run and the batch share one workload memo,
         so each distinct workload of the claim is built once."""
+        from repro.accel.cell_step import RUN_LOG
         from repro.accel.sweep_exec import workload_memo
 
+        runs_before = RUN_LOG.count
         with workload_memo() as memo:
             try:
                 return _claim_and_run(todo, held, now)
             finally:
                 summary["accel_workloads_built"] += memo.built
                 summary["accel_synth_hits"] += memo.hits
+                for record in RUN_LOG.since(runs_before):
+                    for name, key in _RUN_COUNTERS.items():
+                        summary[key] += record[name]
 
     def _claim_and_run(todo: list[str], held, now: float) -> bool:
         from repro.accel.cell_step import CellBatchNotConverged

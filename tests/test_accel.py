@@ -519,3 +519,169 @@ def test_memo_lives_with_its_scope(nested):
     else:
         assert (first.built, first.hits) == (1, 1)
         assert b["arrival"] is a["arrival"]
+
+
+# ---------------------------------------------------------------------------
+# Arrival-window closes: a step that closes a window lands on the close
+# ---------------------------------------------------------------------------
+def _fluid_reference():
+    """The benchmark's plain numpy fluid model, imported from its file."""
+    import importlib.util
+    from pathlib import Path
+
+    path = (Path(__file__).resolve().parents[1] / "chipbench"
+            / "fluid_reference.py")
+    spec = importlib.util.spec_from_file_location("fluid_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _tie_cell(t_entry: float, close: float, eps: float) -> tuple:
+    """A fifo cell whose pending job 1 opens a window of ``eps`` that
+    closes exactly on job 2's arrival; job 0 runs long.  Returns the
+    packed batch and a state at ``t_entry`` with job 0 live."""
+    from repro.accel.cell_step import init_state
+
+    arrival = np.array([0.0, close - eps, close])
+    assert arrival[1] + eps == close and arrival[1] > t_entry
+    ones = np.ones(3)
+    cell = {
+        "arrival": arrival, "m_work": np.array([500.0, 5.0, 5.0]),
+        "m_dur": np.array([500.0, 5.0, 5.0]), "m_tasks": ones,
+        "r_work": np.zeros(3), "r_dur": ones, "r_tasks": np.zeros(3),
+        "weight": ones, "present": ones,
+        "est_m": np.array([500.0, 5.0, 5.0]), "est_r": np.zeros(3),
+        "policy": np.int32(0), "map_slots": np.float64(10.0),
+        "red_slots": np.float64(10.0), "dt_cap": np.float64(np.inf),
+        "eps": np.float64(eps),
+    }
+    packed = pack_cells([cell])
+    state = init_state(packed)
+    state["t"][:] = t_entry
+    return packed, state
+
+
+@pytest.mark.parametrize(
+    "t_entry, close, eps",
+    [
+        # Entry 1e-12 (relative) short of the close, job 1 inside it.
+        (1000.0 * (1 - 1e-12), 1000.0, 1000.0 * 0.5e-12),
+        # Entry far before the close: IEEE's t + (close - t) rounds an
+        # ulp short here, as a chip's float64 can anywhere.
+        (8.141953458148652, 40.30694090928882, 1.5),
+    ],
+    ids=["entry-1e-12-short", "t-plus-dt-rounds-short"],
+)
+def test_cell_step_lands_on_the_window_close(t_entry, close, eps):
+    """The step that closes job 1's window ends on the packed close
+    itself, so job 2, whose arrival ties the close, arrives with job 1
+    in the next step and no further window later."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.accel import cell_step
+
+    packed, state = _tie_cell(t_entry, close, eps)
+    fill = cell_step._make_water_fill("none")
+    with jax.enable_x64(True):
+        params = {k: jnp.asarray(v[0]) for k, v in packed.items()}
+        st = {k: jnp.asarray(v[0]) for k, v in state.items()}
+        one = cell_step._cell_step(params, st, fill)
+        two = cell_step._cell_step(params, one, fill)
+    assert float(one["t"]) == close
+    assert int(one["closes"]) == 1 and int(one["steps"]) == 1
+    # Both jobs of the window hold slots in the second step.
+    m_rem = np.asarray(two["m_rem"])
+    assert m_rem[1] < 5.0 and m_rem[2] < 5.0
+
+
+def _tie_specs(policy: str) -> list[ScenarioSpec]:
+    """fb_scaled at 48 jobs (width 64); a 30 s heartbeat lets the auto
+    window be the median gap, which then ties one arrival per workload."""
+    return [
+        paper_fb_base(seed=seed).override(**{
+            "workload.kind": "fb_scaled", "workload.num_jobs": 48,
+            "cluster.num_machines": 48, "heartbeat": 30.0,
+            "scheduler.policy": policy, "scheduler.error_alpha": alpha,
+            "scheduler.error_seed": 9,
+        })
+        for seed in (2, 5, 8) for alpha in (0.0, 0.3)
+    ]
+
+
+@pytest.mark.parametrize("policy", ACCEL_POLICIES)
+def test_window_ties_match_the_fluid_reference(policy):
+    """Where the auto window closes exactly on an arrival, the program
+    agrees with the float64 reference within 1e-12 of the makespan."""
+    ref = _fluid_reference()
+    cells = [synthesize_cell(s) for s in _tie_specs(policy)]
+    for c in cells:
+        assert c["eps"] > 0
+        assert np.isin(c["arrival"], c["arrival"] + c["eps"]).any()
+    packed = pack_cells(cells)
+    assert packed["arrival"].shape[-1] == 64
+    final = run_packed(packed, impl="lax")
+    rows = {k: np.stack([np.asarray(c[k]) for c in cells]) for k in (
+        "arrival", "m_work", "m_dur", "m_tasks", "r_work", "r_dur",
+        "r_tasks", "est_m", "est_r", "weight", "policy", "map_slots",
+        "red_slots", "dt_cap", "eps")}
+    want = ref.simulate(rows)["comp"]
+    got = final["comp"][:, :48]
+    makespan = want.max(axis=1, keepdims=True)
+    assert np.all(np.abs(got - want) <= 1e-12 * makespan)
+    assert np.all(final["closes"] > 0)
+
+
+# ---------------------------------------------------------------------------
+# run_packed's counters and the worker's sums of them
+# ---------------------------------------------------------------------------
+def test_worker_sums_the_run_counters(tmp_path):
+    """One claim's summary: event steps are its cells' ``accel_steps``;
+    host syncs and scanned row-steps follow each group's chunks."""
+    from repro.accel.cell_step import RUN_LOG
+    from repro.scenarios.store import open_store
+    from repro.scenarios.worker import run_worker
+
+    sweep = SweepSpec(
+        name="counters",
+        base=paper_fb_base().quick(),
+        grids=(SweepSpec.grid(**{"workload.seed": (0, 1),
+                                 "scheduler.policy": ("fifo", "hfsp",
+                                                      "las")}),),
+    )
+    before = RUN_LOG.count
+    store = tmp_path / "s.jsonl"
+    summary = run_worker(sweep, store, compute="accel", deadline=300.0)
+    assert summary["accel_batches"] == 1
+    records = RUN_LOG.since(before)
+    assert len(records) == 2  # the plain group, then the hfsp group
+    results = list(open_store(store).load().values())
+    steps = {"hfsp": [], "plain": []}
+    for r in results:
+        klass = "hfsp" if r["spec"]["scheduler"]["policy"] == "hfsp" \
+            else "plain"
+        steps[klass].append(r["accel_steps"])
+    chunks = {k: -(-max(v) // 512) for k, v in steps.items()}
+    assert summary["accel_event_steps"] == sum(
+        r["accel_steps"] for r in results)
+    assert summary["accel_host_syncs"] == sum(chunks.values())
+    assert summary["accel_scanned_row_steps"] == sum(
+        len(steps[k]) * 512 * chunks[k] for k in steps)
+    assert 0 < summary["accel_window_closes"] <= summary["accel_event_steps"]
+    assert [r["host_syncs"] for r in records] == [chunks["plain"],
+                                                  chunks["hfsp"]]
+
+
+def test_run_log_records_a_batch_that_does_not_drain():
+    from repro.accel.cell_step import RUN_LOG, CellBatchNotConverged
+
+    cell = synthesize_cell(paper_fb_base(seed=0).quick())
+    packed = pack_cells([cell, cell])
+    before = RUN_LOG.count
+    with pytest.raises(CellBatchNotConverged):
+        run_packed(packed, t_chunk=4, max_chunks=2, impl="none")
+    (record,) = RUN_LOG.since(before)
+    assert record["host_syncs"] == 2
+    assert record["scanned_row_steps"] == 2 * 4 * 2
+    assert record["event_steps"] == 2 * 4 * 2

@@ -60,30 +60,42 @@ def test_pallas_waterfill_compiles_for_v5e(one_chip, width):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _compile_chunk_runner(sharding, width: int, impl: str) -> str:
+    with jax.enable_x64(True):
+        params = {
+            k: _spec((BATCH, width), jnp.float64, sharding)
+            for k in _PARAM_KEYS
+        }
+        for k in ("map_slots", "red_slots", "dt_cap", "eps"):
+            params[k] = _spec((BATCH,), jnp.float64, sharding)
+        params["policy"] = _spec((BATCH,), jnp.int32, sharding)
+        row = _spec((BATCH, width), jnp.float64, sharding)
+        state = {
+            "t": _spec((BATCH,), jnp.float64, sharding),
+            "m_rem": row, "r_rem": row, "vm_rem": row, "vr_rem": row,
+            "comp": row,
+            "done": _spec((BATCH, width), np.bool_, sharding),
+            "steps": _spec((BATCH,), jnp.int64, sharding),
+            "closes": _spec((BATCH,), jnp.int64, sharding),
+        }
+        compiled = _chunk_runner(width, 512, impl).lower(
+            params, state
+        ).compile()
+    return compiled.as_text()
+
+
 @pytest.mark.parametrize("impl", ("lax", "none", "pallas"))
 def test_chunk_runner_compiles_for_v5e_f64(one_chip, impl):
     """The whole f64 scan chunk at the paper cell's padded width (100
     jobs -> 128) and the default chunk length; only ``pallas`` carries
     the kernel (``none`` dead-codes the water-fill)."""
-    width = 128
-    with jax.enable_x64(True):
-        params = {
-            k: _spec((BATCH, width), jnp.float64, one_chip)
-            for k in _PARAM_KEYS
-        }
-        for k in ("map_slots", "red_slots", "dt_cap", "eps"):
-            params[k] = _spec((BATCH,), jnp.float64, one_chip)
-        params["policy"] = _spec((BATCH,), jnp.int32, one_chip)
-        row = _spec((BATCH, width), jnp.float64, one_chip)
-        state = {
-            "t": _spec((BATCH,), jnp.float64, one_chip),
-            "m_rem": row, "r_rem": row, "vm_rem": row, "vr_rem": row,
-            "comp": row,
-            "done": _spec((BATCH, width), np.bool_, one_chip),
-            "steps": _spec((BATCH,), jnp.int64, one_chip),
-        }
-        compiled = _chunk_runner(width, 512, impl).lower(
-            params, state
-        ).compile()
-    text = compiled.as_text()
+    text = _compile_chunk_runner(one_chip, 128, impl)
     assert ("tpu_custom_call" in text) == (impl == "pallas")
+
+
+@pytest.mark.parametrize("impl", ("lax", "none"))
+def test_chunk_runner_compiles_for_v5e_f64_at_width_1024(one_chip, impl):
+    """FB-2009's 600 jobs pad to 1024: the W^2 allocator and the ``lax``
+    water-fill (the hfsp group) or none (the plain group) at that width."""
+    text = _compile_chunk_runner(one_chip, 1024, impl)
+    assert "tpu_custom_call" not in text
